@@ -69,6 +69,8 @@ def main(argv=None) -> None:
                     help="comma-separated module names to run (default: all)")
     args = ap.parse_args(argv)
 
+    from repro.launch import use_compile_cache
+    use_compile_cache()
     from benchmarks import (bf_table, ai_curves, householder, givens,
                             tcec_accuracy, tcec_throughput,
                             attention_throughput, policy_sweep,

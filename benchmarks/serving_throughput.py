@@ -8,64 +8,9 @@ cache — the bandwidth the paged layout hands back to the memory-bound
 decode kernel.  Timings run the reduced config on CPU (relative, not
 absolute, numbers); the bytes rows are analytic from the request stream.
 """
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
-
-_SCALE_CHILD = r"""
-import time
-import jax, numpy as np
-from repro.configs import get_config
-from repro.core.context import policy_scope
-from repro.launch.mesh import make_mesh
-from repro.launch.serve import generate_paged
-from repro.models import init_params
-
-devices = len(jax.devices())
-slots = 2 * devices
-cfg = get_config("qwen2-0.5b", reduced=True)
-params = init_params(jax.random.PRNGKey(0), cfg)
-rng = np.random.default_rng(0)
-prompts = [list(rng.integers(0, cfg.vocab, int(rng.integers(4, 13))))
-           for _ in range(2 * slots)]
-mesh = make_mesh((devices, 1), ("data", "model"))
-with policy_scope("bf16x6"):
-    generate_paged(cfg, params, prompts[:2], 2, page_size=8,
-                   max_concurrency=slots, mesh=mesh)      # warm compiles
-    t0 = time.perf_counter()
-    out, _ = generate_paged(cfg, params, prompts, 6, page_size=8,
-                            max_concurrency=slots, mesh=mesh)
-    dt = time.perf_counter() - t0
-print("TOKS", sum(len(v) for v in out.values()) / dt)
-"""
-
-
-def _scaling_rows():
-    """Decode-slots-vs-devices scaling: the same mixed stream served on
-    forced 1/2/4-device CPU meshes (slots = 2 x devices) in subprocesses —
-    the parent's device count is fixed at startup, so each point needs its
-    own ``XLA_FLAGS`` topology.  CPU "devices" share the same cores, so
-    these rows measure dispatch/collective overhead trends, not speedup."""
-    rows = []
-    for devices in (1, 2, 4):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                            f"{devices}")
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        try:
-            res = subprocess.run(
-                [sys.executable, "-c", _SCALE_CHILD], env=env,
-                capture_output=True, text=True, timeout=600)
-            toks = next(float(ln.split()[1]) for ln in
-                        res.stdout.splitlines() if ln.startswith("TOKS"))
-        except (subprocess.SubprocessError, StopIteration, ValueError):
-            continue                          # skip the point, keep the rest
-        rows.append((f"scale_dev{devices}_slots{2 * devices}_tok_s", toks))
-    return rows
-
 
 def _timed_stream(cfg, params, prompts, gen_steps, *, page_size,
                   speculative=None):
@@ -295,7 +240,6 @@ def run():
                  / _cache_bytes_per_step(full, [8192] * 4, 64, False)))
 
     rows.extend(_spec_rows(cfg, params, rng))
-    rows.extend(_scaling_rows())
     return rows
 
 

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 # Mantissa bits contributed per bf16 word.
@@ -38,8 +39,25 @@ def _back(x_bf16: jnp.ndarray) -> jnp.ndarray:
     return x_bf16.astype(jnp.float32)
 
 
+def bf16_round(x: jnp.ndarray) -> jnp.ndarray:
+    """The bf16 rounding of fp32 ``x``, kept in fp32 (round to nearest
+    even), with finite overflow saturated to ±BF16_MAX.
+
+    The XLA splits take their residuals from this value, never from a
+    ``f32(bf16(x))`` round trip: XLA may fold that round trip to ``x``
+    (excess precision), which zeroes every residual word and silently turns
+    a corrected policy into bf16x1 — what the XLA twin of bf16x6 attention
+    measured on a TPU v5e.  ``reduce_precision`` has no such rewrite.
+    """
+    w = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+    return jnp.where(jnp.isfinite(x) & jnp.isinf(w),
+                     jnp.sign(x) * jnp.float32(BF16_MAX), w)
+
+
 def bf16_word(rest: jnp.ndarray) -> jnp.ndarray:
-    """bf16 rounding of a split residual, saturating finite overflow.
+    """bf16 rounding of a split residual, saturating finite overflow — the
+    in-kernel form (Mosaic has no ``reduce_precision``; it does not fold
+    the cast round trip either).
 
     A *finite* fp32 value above BF16_MAX saturates to ±BF16_MAX instead of
     rounding to ±inf, so the Dekker residual ``rest - f32(word)`` stays
@@ -58,19 +76,17 @@ def bf16_word(rest: jnp.ndarray) -> jnp.ndarray:
 def split2(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """FP32 -> (hi, lo) bf16 words; a ~= hi + lo with ~2^-16 rel err."""
     a = a.astype(jnp.float32)
-    hi = bf16_word(a)
-    lo = _to_bf16(a - _back(hi))
-    return hi, lo
+    hi = bf16_round(a)
+    return _to_bf16(hi), _to_bf16(a - hi)
 
 
 def split3(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """FP32 -> (hi, mid, lo) bf16 words; a ~= hi + mid + lo with ~2^-24 rel err."""
     a = a.astype(jnp.float32)
-    hi = bf16_word(a)
-    r1 = a - _back(hi)
-    mid = _to_bf16(r1)
-    lo = _to_bf16(r1 - _back(mid))
-    return hi, mid, lo
+    hi = bf16_round(a)
+    r1 = a - hi
+    mid = bf16_round(r1)
+    return _to_bf16(hi), _to_bf16(mid), _to_bf16(r1 - mid)
 
 
 def reconstruct(*words: jnp.ndarray) -> jnp.ndarray:

@@ -48,23 +48,38 @@ V100_SXM2 = ChipSpec(
 
 CHIPS = {c.name: c for c in (TPU_V5E, A100_SXM4, V100_SXM2)}
 
+#: ``device_kind`` as JAX reports it -> the spec of that TPU.
+TPU_DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
 
 def active_chip() -> ChipSpec:
     """The chip the analytic models target.
 
-    ``REPRO_CHIP`` selects any registered ``ChipSpec`` by name; the default
-    is the v5e (the repo's reference part), which keeps every derived
-    constant — block caps, roofline bounds, tuner scores — identical on the
-    CPU test backend and on the real TPU.
+    On a TPU backend the chip is read from the device: its ``device_kind``
+    must be in ``TPU_DEVICE_KINDS`` (an unknown TPU raises rather than
+    borrowing another part's peaks), and ``REPRO_CHIP``, if set, must name
+    the same spec.  Off the TPU, ``REPRO_CHIP`` selects any registered
+    ``ChipSpec`` by name and the default is the v5e (the repo's reference
+    part), which keeps every derived constant — block caps, roofline
+    bounds, tuner scores — identical on the CPU test backend and on a v5e.
     """
+    import jax
     name = os.environ.get("REPRO_CHIP")
-    if not name:
-        return TPU_V5E
-    try:
-        return CHIPS[name]
-    except KeyError:
+    if name and name not in CHIPS:
         raise KeyError(f"REPRO_CHIP={name!r} is not a registered chip; "
-                       f"known: {sorted(CHIPS)}") from None
+                       f"known: {sorted(CHIPS)}")
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return CHIPS[name] if name else TPU_V5E
+    kind = device.device_kind
+    if kind not in TPU_DEVICE_KINDS:
+        raise ValueError(f"TPU device_kind {kind!r} has no ChipSpec; "
+                         f"known: {sorted(TPU_DEVICE_KINDS)}")
+    chip = TPU_DEVICE_KINDS[kind]
+    if name and name != chip.name:
+        raise ValueError(f"REPRO_CHIP={name!r} contradicts the device "
+                         f"({kind!r} is {chip.name!r})")
+    return chip
 
 
 # ---------------------------------------------------------------------------

@@ -76,10 +76,10 @@ _SCHEDULES = {p: sched for (dt, p), sched in SCHEDULES.items()
               if dt == "bf16"}
 
 
-def _dot(a, b, dimension_numbers, preferred):
+def _dot(a, b, dimension_numbers, preferred, precision=None):
     return jax.lax.dot_general(
         a, b, dimension_numbers=dimension_numbers,
-        preferred_element_type=preferred,
+        preferred_element_type=preferred, precision=precision,
     )
 
 
@@ -131,11 +131,13 @@ def tc_dot_general(
     if policy.backend == "vpu":
         # "FP32 SIMT" analogue: plain FP32 dot on the vector unit.
         return _dot(a.astype(jnp.float32), b.astype(jnp.float32),
-                    dimension_numbers, jnp.float32)
+                    dimension_numbers, jnp.float32,
+                    jax.lax.Precision.HIGHEST)
 
     def _ref(a_, b_):
         return _dot(a_.astype(jnp.float32), b_.astype(jnp.float32),
-                    dimension_numbers, jnp.float32)
+                    dimension_numbers, jnp.float32,
+                    jax.lax.Precision.HIGHEST)
 
     if policy.word_dtype == "int8":
         # Per-tile-scaled int8 words of the running residual; int32 MMA

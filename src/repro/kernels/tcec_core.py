@@ -124,9 +124,11 @@ def policy_dot(a: jnp.ndarray, b: jnp.ndarray, dn=MATMUL_DN, *,
     accumulate the scheduled MXU passes.
     """
     if vpu:
+        # HIGHEST: a default-precision fp32 dot is one bf16 MXU pass on TPU.
         return jax.lax.dot_general(
             a.astype(jnp.float32), b.astype(jnp.float32), dn,
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
     if word_dtype == "int8":
         aw, sa = split_int8_vregs(a.astype(jnp.float32), n_words)
         bw, sb = split_int8_vregs(b.astype(jnp.float32), n_words)
@@ -169,9 +171,6 @@ def tcec_einsum(eq: str, a: jnp.ndarray, b: jnp.ndarray,
 
 
 def compiler_params(semantics: Tuple[str, ...]):
-    """Mosaic compiler params with version-tolerant naming."""
+    """Mosaic compiler params: the grid's dimension semantics."""
     from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except (AttributeError, TypeError):  # older naming
-        return pltpu.TPUCompilerParams(dimension_semantics=semantics)
+    return pltpu.CompilerParams(dimension_semantics=semantics)

@@ -85,7 +85,8 @@ def _tcec_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_words, schedule, nk, vpu,
         # "FP32 SIMT" analogue: plain fp32 dot, no splitting, no MXU passes.
         acc_ref[...] += jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
     elif word_dtype == "int8":
         # Quantized TCEC: per-(bm,bk)/(bk,bn)-tile int8 words generated in
         # VREGs, int32 MMA passes rescaled to fp32 per schedule term.
@@ -536,7 +537,8 @@ def _fused_kernel(*refs, n_words, schedule, nk, vpu, word_dtype, frag_rule,
     if vpu:
         acc_ref[...] += jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)
     elif word_dtype == "int8":
         aw, sa = _split_int8_vregs(a, n_words)
         bw, sb = _split_int8_vregs(b, n_words)

@@ -1,5 +1,24 @@
 """Launchers: mesh construction, dry-run driver, train/serve entry points."""
 import contextlib
+import os
+from pathlib import Path
+
+#: Persistent compilation cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset.  A fixed path in the checkout: the directory is part of the
+#: cache's key, so a path that moved between runs would never hit.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is set here; otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    Call before the first compile."""
+    if "JAX_COMPILATION_CACHE_DIR" in os.environ:
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def add_policy_args(ap) -> None:

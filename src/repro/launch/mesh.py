@@ -20,12 +20,8 @@ import jax
 
 
 def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=Auto`` where the jax version has it (>= 0.5); older
-    versions predate explicit axis types and default to the same behavior."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    """Every mesh axis is ``Auto`` (GSPMD propagates the shardings)."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

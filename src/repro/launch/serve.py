@@ -34,7 +34,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, ARCH_IDS
 from repro.data.pipeline import make_frontend_inputs
-from repro.launch import add_policy_args, policy_scope_from_args
+from repro.launch import (add_policy_args, policy_scope_from_args,
+                          use_compile_cache)
 from repro.launch.mesh import make_host_mesh, make_mesh, parse_mesh_shape
 from repro.models import init_params, prefill, decode_step, init_decode_caches
 from repro.models.base import activation_sharding
@@ -215,6 +216,7 @@ def main(argv=None):
                          "platform_device_count=N on CPU) to turn it on")
     add_policy_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     if args.mesh:

@@ -18,7 +18,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, ARCH_IDS
 from repro.data.pipeline import DataConfig
-from repro.launch import add_policy_args, policy_scope_from_args
+from repro.launch import (add_policy_args, policy_scope_from_args,
+                          use_compile_cache)
 from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.base import activation_sharding
@@ -43,6 +44,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     add_policy_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     mesh = (make_production_mesh() if args.production_mesh

@@ -104,13 +104,8 @@ def run_pipeline(mesh: Mesh, stage_fn, stage_params_stacked, x_micro,
         P(),
     )
     body = lambda sp, x: fn(jax.tree.map(lambda a: a[0], sp), x)
-    if hasattr(jax, "shard_map"):              # jax >= 0.6
-        mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=P(), check_vma=False)
-    else:                                      # jax 0.4.x/0.5.x spelling
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=P(), check_rep=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=P(), check_vma=False)
     return mapped(stage_params_stacked, x_micro)
 
 

@@ -36,7 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.context import resolve_policy
 from repro.core.policy import TcecPolicy
-from repro.kernels.tcec_core import policy_dot, dot_params
+from repro.kernels.tcec_core import compiler_params, dot_params, policy_dot
 from repro import tcec
 from .paged_cache import gather_pages
 
@@ -62,21 +62,25 @@ def _corrected(pol: TcecPolicy) -> bool:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
-                  page, npages, scale, dot_kw, has_rope, quantized):
-    rest = list(rest)
+def _paged_kernel(bt_ref, sl_ref, *refs, page, npages, kvh, scale, dot_kw,
+                  has_rope, quantized):
+    refs = list(refs)
     ks_ref = vs_ref = k2s_ref = None
     if quantized:
-        ks_ref, vs_ref = rest[:2]
-        rest = rest[2:]
+        # per-(request, logical page) scales, scalar-prefetched into SMEM
+        ks_ref, vs_ref = refs[:2]
+        refs = refs[2:]
+        if has_rope:
+            k2s_ref, refs = refs[0], refs[1:]
+    q_ref, k_ref, v_ref = refs[:3]
+    refs = refs[3:]
+    q2_ref = k2_ref = None
     if has_rope:
-        q2_ref, k2_ref = rest[:2]
-        rest = rest[2:]
-        if quantized:
-            k2s_ref, rest = rest[0], rest[1:]
-    o_ref, m_ref, l_ref, acc_ref = rest
+        q2_ref, k2_ref = refs[:2]
+        refs = refs[2:]
+    o_ref, m_ref, l_ref, acc_ref = refs
     bi = pl.program_id(0)
-    ji = pl.program_id(2)
+    ji = pl.program_id(1)
 
     @pl.when(ji == 0)
     def _init():
@@ -84,44 +88,50 @@ def _paged_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (rep, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (page, d)
-    v = v_ref[0, :, 0].astype(jnp.float32)           # (page, dv)
-    if quantized:
-        # int8 page payloads: dequantize at this page's scalar scale right
-        # after the page DMA — the gather twin multiplies the same factor.
-        k = k * ks_ref[0, 0]
-        v = v * vs_ref[0, 0]
-
-    # QK^T at policy-selected precision (split words live in VREGs).
-    s = policy_dot(q, k, _QK_DN, **dot_kw)
-    if has_rope:
-        q2 = q2_ref[0, 0].astype(jnp.float32)        # (rep, d2)
-        k2 = k2_ref[0, :, 0].astype(jnp.float32)     # (page, d2)
+    seq_len = sl_ref[bi]
+    # One block holds every kv head of the page (the pool's trailing
+    # (kvh, d) dims are whole, which is what lets Mosaic tile the DMA);
+    # the heads are a static loop over the leading scratch axis.
+    for g in range(kvh):
+        q = q_ref[0, g].astype(jnp.float32)          # (rep, d)
+        k = k_ref[0, :, g].astype(jnp.float32)       # (page, d)
+        v = v_ref[0, :, g].astype(jnp.float32)       # (page, dv)
         if quantized:
-            k2 = k2 * k2s_ref[0, 0]
-        s = s + policy_dot(q2, k2, _QK_DN, **dot_kw)
-    s = s * scale
+            # int8 page payloads: dequantize at this page's scale right
+            # after the page DMA — the gather twin multiplies the same
+            # factor.
+            k = k * ks_ref[bi, ji]
+            v = v * vs_ref[bi, ji]
 
-    # Structural-rule length mask: col = absolute kv position of this page.
-    cols = ji * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(cols < sl_ref[bi], s, NEG_INF)
+        # QK^T at policy-selected precision (split words live in VREGs).
+        s = policy_dot(q, k, _QK_DN, **dot_kw)
+        if has_rope:
+            q2 = q2_ref[0, g].astype(jnp.float32)    # (rep, d2)
+            k2 = k2_ref[0, :, g].astype(jnp.float32) # (page, d2)
+            if quantized:
+                k2 = k2 * k2s_ref[bi, ji]
+            s = s + policy_dot(q2, k2, _QK_DN, **dot_kw)
+        s = s * scale
 
-    m_prev = m_ref[...]                              # (rep, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # Rows with no valid column yet (m_new == NEG_INF) must contribute
-    # nothing: exp(s - m_new) would be 1 at every masked position.
-    p = jnp.where(m_new > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + policy_dot(p, v, _PV_DN, **dot_kw)
-    m_ref[...] = m_new
+        # Structural-rule length mask: col = absolute kv position.
+        cols = ji * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < seq_len, s, NEG_INF)
+
+        m_prev = m_ref[g]                            # (rep, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # Rows with no valid column yet (m_new == NEG_INF) must contribute
+        # nothing: exp(s - m_new) would be 1 at every masked position.
+        p = jnp.where(m_new > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_ref[g] = acc_ref[g] * alpha + policy_dot(p, v, _PV_DN, **dot_kw)
+        m_ref[g] = m_new
 
     @pl.when(ji == npages - 1)
     def _done():
         l = l_ref[...]
         # Fully-masked rows (seq_len == 0) emit exact zeros, not 0/0.
-        o_ref[0, 0] = jnp.where(
+        o_ref[0] = jnp.where(
             l > 0.0, acc_ref[...] / jnp.where(l > 0.0, l, 1.0), 0.0)
 
 
@@ -136,71 +146,60 @@ def _paged_pallas(q, k_pages, v_pages, q2, k2_pages, block_table, seq_lens,
     npages = block_table.shape[1]
     has_rope = q2 is not None
     quantized = k_scales is not None
+    block_table = block_table.astype(jnp.int32)
 
-    # kv heads ride the grid (GQA: h = kvh * rep, no repeated-head copies);
-    # the page axis is innermost and 'arbitrary' so (m, l, acc) scratch
-    # carries across a request's pages.
-    def kv_map(b_, g, j, bt, sl):
-        del sl
-        return (bt[b_, j], 0, g, 0)
+    # Grid (request, logical page): the page axis is innermost and
+    # 'arbitrary' so the (m, l, acc) scratch carries across a request's
+    # pages.  Each step DMAs one physical page with all its kv heads (GQA:
+    # h = kvh * rep, no repeated-head copies).
+    def kv_map(b_, j, bt, *_):
+        return (bt[b_, j], 0, 0, 0)
 
-    def q_map(b_, g, j, bt, sl):
-        del j, bt, sl
-        return (b_, g, 0, 0)
+    def q_map(b_, j, *_):
+        del j
+        return (b_, 0, 0, 0)
 
-    def scale_map(b_, g, j, bt, sl):
-        del g, sl
-        return (bt[b_, j], 0)
-
-    scale_spec = pl.BlockSpec((1, 1), scale_map)
-
+    prefetch = [block_table, seq_lens.astype(jnp.int32)]
+    if quantized:
+        # Per-page scales gathered to the block table's (b, npages) shape
+        # ride in SMEM beside it: one scalar per grid step, no VMEM tile.
+        prefetch += [k_scales[block_table], v_scales[block_table]]
+        if has_rope:
+            prefetch += [k2_scales[block_table]]
     in_specs = [
-        pl.BlockSpec((1, 1, rep, d), q_map),
-        pl.BlockSpec((1, page, 1, d), kv_map),
-        pl.BlockSpec((1, page, 1, dv), kv_map),
+        pl.BlockSpec((1, kvh, rep, d), q_map),
+        pl.BlockSpec((1, page, kvh, d), kv_map),
+        pl.BlockSpec((1, page, kvh, dv), kv_map),
     ]
     operands = [q, k_pages, v_pages]
-    if quantized:
-        # per-page fp32 scales ride as (P, 1) blocks resolved through the
-        # same block-table index map as their pages.
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales.reshape(-1, 1), v_scales.reshape(-1, 1)]
     if has_rope:
         d2 = q2.shape[-1]
         in_specs += [
-            pl.BlockSpec((1, 1, rep, d2), q_map),
-            pl.BlockSpec((1, page, 1, d2), kv_map),
+            pl.BlockSpec((1, kvh, rep, d2), q_map),
+            pl.BlockSpec((1, page, kvh, d2), kv_map),
         ]
         operands += [q2, k2_pages]
-        if quantized:
-            in_specs += [scale_spec]
-            operands += [k2_scales.reshape(-1, 1)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kvh, npages),
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, npages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, dv), q_map),
+        out_specs=pl.BlockSpec((1, kvh, rep, dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, dv), jnp.float32),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, 1), jnp.float32),
+            pltpu.VMEM((kvh, rep, dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_kernel, page=page, npages=npages,
+        functools.partial(_paged_kernel, page=page, npages=npages, kvh=kvh,
                           scale=scale, dot_kw=dot_params(policy),
                           has_rope=has_rope, quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, rep, dv), jnp.float32),
-        compiler_params=_compiler_params(),
+        compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), seq_lens.astype(jnp.int32), *operands)
-
-
-def _compiler_params():
-    from repro.kernels.tcec_core import compiler_params
-    return compiler_params(("parallel", "parallel", "arbitrary"))
+    )(*prefetch, *operands)
 
 
 def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,

@@ -131,14 +131,16 @@ def _contract(eq: str, a: jnp.ndarray, b: jnp.ndarray, pol: TcecPolicy,
     width on TPU (§Perf H5: the tensor-parallel all-reduce of dx then runs
     at bf16 wire width instead of reducing fp32 and casting after).
     """
-    f32 = jnp.float32
+    f32, HIGHEST = jnp.float32, jax.lax.Precision.HIGHEST
+    # fp32 contractions stand for the fp32 reference: HIGHEST keeps them
+    # fp32 on the TPU, where default precision is one bf16 MXU pass.
     if pol.backend == "vpu":
         return jnp.einsum(eq, a.astype(f32), b.astype(f32),
-                          preferred_element_type=f32)
+                          preferred_element_type=f32, precision=HIGHEST)
 
     def _ref(a_, b_):
         return jnp.einsum(eq, a_.astype(f32), b_.astype(f32),
-                          preferred_element_type=f32)
+                          preferred_element_type=f32, precision=HIGHEST)
 
     if pol.word_dtype == "int8":
         # Per-tile-scaled int8 words of the running residual (both

@@ -274,3 +274,51 @@ def test_paged_candidates_respect_seq_bound():
     cands = tune.paged_candidates(16)
     assert cands and all(c.page_size <= 16 for c in cands)
     assert tune.paged_candidates(1)   # never empty
+
+
+# ---------------------------------------------------------------------------
+# Chip identity: the analytic target is read from the device on a TPU.
+# ---------------------------------------------------------------------------
+
+class _StubDevice:
+    def __init__(self, platform, device_kind):
+        self.platform = platform
+        self.device_kind = device_kind
+
+
+def _stub_devices(monkeypatch, platform, kind):
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: [_StubDevice(platform, kind)])
+
+
+def test_active_chip_reads_tpu_device_kind(monkeypatch):
+    from repro.core.roofline import TPU_V5E
+    monkeypatch.delenv("REPRO_CHIP", raising=False)
+    _stub_devices(monkeypatch, "tpu", "TPU v5 lite")
+    assert active_chip() is TPU_V5E
+    monkeypatch.setenv("REPRO_CHIP", "tpu-v5e")
+    assert active_chip() is TPU_V5E
+
+
+def test_active_chip_raises_on_unknown_tpu_kind(monkeypatch):
+    monkeypatch.delenv("REPRO_CHIP", raising=False)
+    _stub_devices(monkeypatch, "tpu", "TPU v9 hypothetical")
+    with pytest.raises(ValueError, match="TPU v9 hypothetical"):
+        active_chip()
+
+
+def test_active_chip_raises_when_repro_chip_contradicts_device(monkeypatch):
+    monkeypatch.setenv("REPRO_CHIP", "a100-sxm4")
+    _stub_devices(monkeypatch, "tpu", "TPU v5 lite")
+    with pytest.raises(ValueError, match="contradicts"):
+        active_chip()
+
+
+def test_active_chip_off_tpu_keeps_v5e_default_and_repro_chip(monkeypatch):
+    from repro.core.roofline import A100_SXM4, TPU_V5E
+    monkeypatch.delenv("REPRO_CHIP", raising=False)
+    _stub_devices(monkeypatch, "cpu", "cpu")
+    assert active_chip() is TPU_V5E
+    monkeypatch.setenv("REPRO_CHIP", "a100-sxm4")
+    assert active_chip() is A100_SXM4
