@@ -13,6 +13,17 @@ One engine ``step()`` executes one scheduler tick:
      through the paged chunked-prefill path,
   4. decode: ONE batched ``decode_step_paged`` over every slot.
 
+Each phase runs under a host span (``jax.profiler.TraceAnnotation``):
+``serving.step`` holds one tick (stats ``n_admit``, ``n_prefill``,
+``n_decode``) and, inside it, ``serving.schedule``, ``serving.admit``
+(``rid``, ``slot``), ``serving.prefill`` (``rid``, ``start``, ``tokens``),
+``serving.decode`` (``lanes``), ``serving.propose``, ``serving.sync``
+(``kind`` ``first_token`` with its ``rid``, ``decode`` or ``verify``: the
+host blocked on device results) and ``serving.record``.  The decode step,
+the prefill chunk and the verify step are the programs
+``jit_paged_decode_step``, ``jit_paged_prefill_chunk`` and
+``jit_paged_verify_step``.
+
 Slots not decoding this tick ride the batched step as ghost lanes.  Their
 safety rests on two invariants, not on the scratch page alone: (a) *free*
 slots point their whole block-table row at ``NULL_PAGE``, so their writes
@@ -48,6 +59,11 @@ from .paged_cache import (NULL_PAGE, copy_page, pages_needed,
 from .scheduler import Request, Scheduler, StepPlan
 
 __all__ = ["PagedServingEngine"]
+
+# Host spans of one tick (``serving.*``), written into a running
+# ``jax.profiler`` trace on the device planes' clock; without a profiler
+# each costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
 
 _SEQ_MIXERS = ("attn", "mla")
 
@@ -194,19 +210,36 @@ class PagedServingEngine:
         self._last_tok = np.zeros((max_concurrency,), np.int32)
         self._next_rid = 0
 
-        self._decode_fn = jax.jit(
-            lambda p, t, c, bt, sl, act, li: decode_step_paged(
-                p, t, c, bt, sl, cfg, active=act, logit_index=li),
-            donate_argnums=(2,))
+        # Two jits of the same paged step, one per shape the engine drives,
+        # so a profiler trace tells the decode step from a prefill chunk by
+        # its program name (jit_paged_decode_step, jit_paged_prefill_chunk).
+        def paged_decode_step(p, t, c, bt, sl, act, li):
+            return decode_step_paged(p, t, c, bt, sl, cfg, active=act,
+                                     logit_index=li)
+
+        def paged_prefill_chunk(*args):
+            return paged_decode_step(*args)
+
+        def paged_verify_step(p, t, c, bt, sl, act, nd):
+            return verify_step_paged(p, t, c, bt, sl, cfg, n_draft=nd,
+                                     active=act)
+
+        self._decode_jit = jax.jit(paged_decode_step, donate_argnums=(2,))
+        self._chunk_jit = jax.jit(paged_prefill_chunk, donate_argnums=(2,))
+        self._decode_fn = self._paged_step
         self._prefill_fn = jax.jit(functools.partial(prefill, cfg=cfg))
         self._write_fn = jax.jit(write_prefill_prefix, donate_argnums=(0,))
         self._copy_fn = jax.jit(copy_page, donate_argnums=(0,))
         self._reset_scales_fn = jax.jit(reset_page_scales,
                                         donate_argnums=(0,))
-        self._verify_fn = jax.jit(
-            lambda p, t, c, bt, sl, act, nd: verify_step_paged(
-                p, t, c, bt, sl, cfg, n_draft=nd, active=act),
-            donate_argnums=(2,))
+        self._verify_fn = jax.jit(paged_verify_step, donate_argnums=(2,))
+
+    def _paged_step(self, params, tokens, caches, bt, sl, act, li):
+        """The paged model step: one token per slot runs the decode jit,
+        several (a prefill chunk) the chunk jit.  Returns ``(logits,
+        caches)``."""
+        fn = self._decode_jit if tokens.shape[1] == 1 else self._chunk_jit
+        return fn(params, tokens, caches, bt, sl, act, li)
 
     def _scope(self):
         """Mesh + activation-sharding context for every jitted model call
@@ -264,49 +297,71 @@ class PagedServingEngine:
     # -- one tick -----------------------------------------------------------
 
     def step(self) -> StepPlan:
-        with self._scope():
-            return self._step()
+        with self._scope(), _span("serving.step") as span:
+            with _span("serving.schedule"):
+                plan = self.scheduler.step()
+            span.set_metadata(n_admit=len(plan.admit),
+                              n_prefill=len(plan.prefill),
+                              n_decode=len(plan.decode))
+            self._step(plan)
+        return plan
 
-    def _step(self) -> StepPlan:
-        sched = self.scheduler
-        plan = sched.step()
+    def _step(self, plan: StepPlan) -> None:
         for rid, slot in plan.evict:
             self.block_table[slot] = NULL_PAGE
             self.seq_lens[slot] = 0
             if self.proposer is not None:
                 self.proposer.release(rid)
         for rid, slot in plan.admit:
-            st = sched.active[rid]
-            if self.proposer is not None:
-                self.proposer.register(rid, st.req.prompt)
-            row = sched.block_row(rid)
-            self.block_table[slot] = NULL_PAGE
-            self.block_table[slot, :len(row)] = row
-            if st.boundary_src is not None:
-                # COW boundary: clone the cached page holding the span this
-                # request diverges inside into its first private page; its
-                # own tokens overwrite the clone from offset
-                # cached_upto % page_size on.
-                self.caches = self._copy_fn(
-                    self.caches, self._host(st.boundary_src),
-                    self._host(row[st.n_shared]))
-            if self.quantized_kv:
-                # recycled pages keep their stale scale (nothing is zeroed
-                # on eviction) and scales only ever grow mid-residency —
-                # zero the *fresh* pages' scales at admission so each
-                # tenant quantizes against its own magnitudes.  Shared
-                # prefix pages (and the COW boundary clone, which holds
-                # live tokens at the source's scale) must keep theirs.
-                keep = st.n_shared + (1 if st.boundary_src is not None else 0)
-                fresh = list(row[keep:])
-                fresh += [NULL_PAGE] * (self.npages_per_seq - len(fresh))
-                self.caches = self._reset_scales_fn(self.caches,
-                                                    self._host(fresh))
-            self.seq_lens[slot] = st.cached_upto
-
+            with _span("serving.admit", rid=rid, slot=slot):
+                self._admit(rid, slot)
         for chunk in plan.prefill:
-            st = sched.active[chunk.rid]
-            tokens = list(st.req.prompt[chunk.start:chunk.end])
+            self._prefill(chunk)
+        if plan.decode and self.spec is not None:
+            self._spec_decode(plan)
+        elif plan.decode:
+            self._decode(plan)
+
+    def _admit(self, rid: int, slot: int) -> None:
+        """Install an admitted request's block-table row (and, with prefix
+        caching or int8 pages, its COW clone and fresh page scales)."""
+        st = self.scheduler.active[rid]
+        if self.proposer is not None:
+            self.proposer.register(rid, st.req.prompt)
+        row = self.scheduler.block_row(rid)
+        self.block_table[slot] = NULL_PAGE
+        self.block_table[slot, :len(row)] = row
+        if st.boundary_src is not None:
+            # COW boundary: clone the cached page holding the span this
+            # request diverges inside into its first private page; its
+            # own tokens overwrite the clone from offset
+            # cached_upto % page_size on.
+            self.caches = self._copy_fn(
+                self.caches, self._host(st.boundary_src),
+                self._host(row[st.n_shared]))
+        if self.quantized_kv:
+            # recycled pages keep their stale scale (nothing is zeroed
+            # on eviction) and scales only ever grow mid-residency —
+            # zero the *fresh* pages' scales at admission so each
+            # tenant quantizes against its own magnitudes.  Shared
+            # prefix pages (and the COW boundary clone, which holds
+            # live tokens at the source's scale) must keep theirs.
+            keep = st.n_shared + (1 if st.boundary_src is not None else 0)
+            fresh = list(row[keep:])
+            fresh += [NULL_PAGE] * (self.npages_per_seq - len(fresh))
+            self.caches = self._reset_scales_fn(self.caches,
+                                                self._host(fresh))
+        self.seq_lens[slot] = st.cached_upto
+
+    def _prefill(self, chunk) -> None:
+        """Run one prompt chunk; after the last one, sync on its first
+        token."""
+        sched = self.scheduler
+        st = sched.active[chunk.rid]
+        tokens = list(st.req.prompt[chunk.start:chunk.end])
+        real = len(tokens)
+        with _span("serving.prefill", rid=chunk.rid, start=chunk.start,
+                   tokens=real):
             if sched.prefill_chunk is None and not self.prefix_cache:
                 # single-shot: the standard prefill (same numerics as the
                 # dense serve path), scattered into this request's pages
@@ -325,7 +380,6 @@ class PagedServingEngine:
                 # causally inert for the real rows; pad K/V appends land
                 # past the real positions and are overwritten (or
                 # scratch-absorbed past the block row) before any read.
-                real = len(tokens)
                 if sched.prefill_chunk is not None \
                         and real < sched.prefill_chunk:
                     tokens = tokens + [0] * (sched.prefill_chunk - real)
@@ -335,23 +389,26 @@ class PagedServingEngine:
                     self._host(self.block_table[chunk.slot][None]),
                     self._host(self.seq_lens[chunk.slot][None]), None,
                     self._host([real - 1]))
-            self.seq_lens[chunk.slot] = chunk.end
-            if chunk.last:
-                # only the final chunk's logits are consumed (one host sync)
-                tok = int(jnp.argmax(logits[0]))
-                sched.record_prefill(chunk.rid, chunk.end, first_token=tok)
-                self._last_tok[chunk.slot] = tok
-                if self.proposer is not None \
-                        and not sched.active[chunk.rid].finished:
-                    # feed the first emitted token (unless it finished the
-                    # request outright — its state is about to be released)
-                    self.proposer.observe(chunk.rid, [tok])
-            else:
+        self.seq_lens[chunk.slot] = chunk.end
+        if not chunk.last:
+            with _span("serving.record"):
                 sched.record_prefill(chunk.rid, chunk.end)
+            return
+        # only the final chunk's logits are consumed (one host sync)
+        with _span("serving.sync", kind="first_token", rid=chunk.rid):
+            tok = int(jnp.argmax(logits[0]))
+        with _span("serving.record"):
+            sched.record_prefill(chunk.rid, chunk.end, first_token=tok)
+            self._last_tok[chunk.slot] = tok
+            if self.proposer is not None \
+                    and not sched.active[chunk.rid].finished:
+                # feed the first emitted token (unless it finished the
+                # request outright — its state is about to be released)
+                self.proposer.observe(chunk.rid, [tok])
 
-        if plan.decode and self.spec is not None:
-            self._spec_decode(plan)
-        elif plan.decode:
+    def _decode(self, plan: StepPlan) -> None:
+        """One batched decode step over every slot."""
+        with _span("serving.decode", lanes=len(plan.decode)):
             toks = self._host(self._last_tok[:, None])
             active = np.zeros((len(self.seq_lens),), bool)
             for _, slot in plan.decode:
@@ -360,13 +417,14 @@ class PagedServingEngine:
                 self.params, toks, self.caches,
                 self._host(self.block_table), self._host(self.seq_lens),
                 self._host(active, jnp.bool_), None)
+        with _span("serving.sync", kind="decode"):
             next_tok = np.asarray(jnp.argmax(logits, axis=-1))
+        with _span("serving.record"):
             for rid, slot in plan.decode:
                 self.seq_lens[slot] += 1
                 tok = int(next_tok[slot])
-                sched.record_decode(rid, tok)
+                self.scheduler.record_decode(rid, tok)
                 self._last_tok[slot] = tok
-        return plan
 
     def _spec_decode(self, plan: StepPlan) -> None:
         """One speculative verify tick over every decode-phase slot.
@@ -383,31 +441,36 @@ class PagedServingEngine:
         toks[:, 0] = self._last_tok
         n_draft = np.zeros((b,), np.int32)
         active = np.zeros((b,), bool)
-        for rid, slot in plan.decode:
-            active[slot] = True
-            st = sched.active[rid]
-            budget = min(k, st.req.max_new_tokens - st.generated - 1)
-            drafts = self.proposer.propose(rid, budget) if budget > 0 else []
-            n_draft[slot] = len(drafts)
-            toks[slot, 1:1 + len(drafts)] = drafts
-        targets, n_acc, self.caches = self._verify_fn(
-            self.params, self._host(toks), self.caches,
-            self._host(self.block_table), self._host(self.seq_lens),
-            self._host(active, jnp.bool_), self._host(n_draft))
-        targets = np.asarray(targets)
-        n_acc = np.asarray(n_acc)
+        with _span("serving.propose"):
+            for rid, slot in plan.decode:
+                active[slot] = True
+                st = sched.active[rid]
+                budget = min(k, st.req.max_new_tokens - st.generated - 1)
+                drafts = self.proposer.propose(rid, budget) \
+                    if budget > 0 else []
+                n_draft[slot] = len(drafts)
+                toks[slot, 1:1 + len(drafts)] = drafts
+        with _span("serving.decode", lanes=len(plan.decode)):
+            targets, n_acc, self.caches = self._verify_fn(
+                self.params, self._host(toks), self.caches,
+                self._host(self.block_table), self._host(self.seq_lens),
+                self._host(active, jnp.bool_), self._host(n_draft))
+        with _span("serving.sync", kind="verify"):
+            targets = np.asarray(targets)
+            n_acc = np.asarray(n_acc)
         stats = self._spec_stats
-        for rid, slot in plan.decode:
-            n_out = int(n_acc[slot]) + 1
-            out = [int(t) for t in targets[slot, :n_out]]
-            committed = sched.record_decode_burst(rid, out)
-            self.seq_lens[slot] += committed
-            self._last_tok[slot] = out[committed - 1]
-            if not sched.active[rid].finished:
-                self.proposer.observe(rid, out[:committed])
-            stats.proposed += int(n_draft[slot])
-            stats.accepted += n_out - 1
-            stats.emitted += committed
+        with _span("serving.record"):
+            for rid, slot in plan.decode:
+                n_out = int(n_acc[slot]) + 1
+                out = [int(t) for t in targets[slot, :n_out]]
+                committed = sched.record_decode_burst(rid, out)
+                self.seq_lens[slot] += committed
+                self._last_tok[slot] = out[committed - 1]
+                if not sched.active[rid].finished:
+                    self.proposer.observe(rid, out[:committed])
+                stats.proposed += int(n_draft[slot])
+                stats.accepted += n_out - 1
+                stats.emitted += committed
         stats.ticks += 1
 
     @property

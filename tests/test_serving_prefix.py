@@ -422,10 +422,11 @@ def test_prefix_cached_golden_under_page_backpressure(tiny_model):
 
 
 def test_chunked_prefill_compile_count_is_bounded(tiny_model):
-    """Tail chunks are right-padded to ``prefill_chunk``, so the jitted
-    paged step compiles exactly two shapes — the chunk shape and the
-    decode shape — across arbitrary prompt lengths (regression: unpadded,
-    every distinct final-chunk length re-traced)."""
+    """Tail chunks are right-padded to ``prefill_chunk``, so each of the
+    paged step's two programs compiles one shape — the chunk program the
+    chunk shape, the decode program the decode shape — across arbitrary
+    prompt lengths (regression: unpadded, every distinct final-chunk
+    length re-traced)."""
     from repro.core.context import policy_scope
     cfg, params = tiny_model
     rng = np.random.default_rng(2)
@@ -435,7 +436,8 @@ def test_chunked_prefill_compile_count_is_bounded(tiny_model):
         eng, out = _run_engine(cfg, params, prompts, gens,
                                prefix_cache=True, prefill_chunk=4)
     assert sorted(out) == list(range(len(prompts)))
-    assert eng._decode_fn._cache_size() <= 2
+    assert eng._decode_jit._cache_size() <= 1
+    assert eng._chunk_jit._cache_size() <= 1
 
 
 def test_prefix_cache_rejects_recurrent_mixers():
