@@ -360,7 +360,8 @@ def gqa_apply(p, x: jnp.ndarray, cfg: ArchConfig, positions: jnp.ndarray,
               emit_kv: bool = False,
               kv_len: Optional[int] = None,
               block_table: Optional[jnp.ndarray] = None,
-              seq_lens: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+              seq_lens: Optional[jnp.ndarray] = None,
+              layer=None) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """GQA attention. cache given -> decode (x is (b, 1, d)), returns updated
     cache.  is_cross: cross-attention (kv from kv_source at prefill, from the
     precomputed cache at decode; no rope).  kv_len masks right-padded
@@ -370,7 +371,12 @@ def gqa_apply(p, x: jnp.ndarray, cfg: ArchConfig, positions: jnp.ndarray,
     ``repro.serving``) decodes through the block table: the new K/V are
     appended at each request's ``seq_lens`` position and attention gathers
     pages via ``paged_decode_attention`` (s == 1) or the chunked-prefill
-    path (s > 1), at the same ``"attn"``-site policy as the dense path."""
+    path (s > 1), at the same ``"attn"``-site policy as the dense path.
+    The pools arrive whole, stacked over layer groups ``(g, P, page, kvh,
+    w)`` (rows lane-padded to ``w >= d``), with this layer's index
+    ``layer``: the append writes that layer in place and attention reads it
+    there, and the updated stacked pools are returned (``None``:
+    single-layer pools ``(P, page, kvh, w)``)."""
     b, s, d = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     pol = "attn"
@@ -405,36 +411,41 @@ def gqa_apply(p, x: jnp.ndarray, cfg: ArchConfig, positions: jnp.ndarray,
         # through the block table (lazy import: serving depends on models)
         from repro.serving import paged_cache as _pc
         from repro.serving import paged_attention as _pa
-        # pool sharding: kv heads over "model" (when divisible), page and
-        # offset axes never — under a mesh the constraint keeps GSPMD from
-        # re-replicating the appended pool across the model axis mid-step
-        # (matches parallel.sharding.paged_cache_pspecs).
+        # pool sharding: kv heads over "model" (when divisible), layer,
+        # page and offset axes never — under a mesh the constraint keeps
+        # GSPMD from re-replicating the appended pool across the model axis
+        # mid-step (matches parallel.sharding.paged_cache_pspecs).
         k_scales = v_scales = None
         if "k_scales" in cache:
             # quantized pools: int8 payload + per-page fp32 scale sidecar
             kp, k_scales = _pc.append_pages(cache["k_pages"], k, block_table,
                                             seq_lens,
-                                            scales=cache["k_scales"])
+                                            scales=cache["k_scales"],
+                                            layer=layer)
             vp, v_scales = _pc.append_pages(cache["v_pages"], v, block_table,
                                             seq_lens,
-                                            scales=cache["v_scales"])
+                                            scales=cache["v_scales"],
+                                            layer=layer)
         else:
-            kp = _pc.append_pages(cache["k_pages"], k, block_table, seq_lens)
-            vp = _pc.append_pages(cache["v_pages"], v, block_table, seq_lens)
-        k_pages = shard_hint(kp, None, None, "kv", None)
-        v_pages = shard_hint(vp, None, None, "kv", None)
+            kp = _pc.append_pages(cache["k_pages"], k, block_table, seq_lens,
+                                  layer=layer)
+            vp = _pc.append_pages(cache["v_pages"], v, block_table, seq_lens,
+                                  layer=layer)
+        lead = (None,) * (kp.ndim - 3)
+        k_pages = shard_hint(kp, *lead, None, "kv", None)
+        v_pages = shard_hint(vp, *lead, None, "kv", None)
         if s == 1:
             o = _pa.paged_decode_attention(
                 q[:, 0], k_pages, v_pages, block_table,
                 seq_lens.astype(jnp.int32) + 1,
-                k_scales=k_scales, v_scales=v_scales)[:, None]
+                k_scales=k_scales, v_scales=v_scales, layer=layer)[:, None]
         else:
             row_pos = seq_lens[:, None].astype(jnp.int32) \
                 + jnp.arange(s, dtype=jnp.int32)[None]
             o = _pa.paged_prefill_attention(q, k_pages, v_pages,
                                             block_table, row_pos,
                                             k_scales=k_scales,
-                                            v_scales=v_scales)
+                                            v_scales=v_scales, layer=layer)
         o = shard_hint(o, "batch", None, "heads", None)
         y = dense(o.reshape(b, s, h * hd), p["wo"], pol)
         new_cache = {"k_pages": k_pages, "v_pages": v_pages}
@@ -504,7 +515,11 @@ def mla_apply(p, x: jnp.ndarray, cfg: ArchConfig, positions: jnp.ndarray,
               cache_index: Optional[jnp.ndarray] = None,
               causal: bool = True, kv_source=None,
               block_table: Optional[jnp.ndarray] = None,
-              seq_lens: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+              seq_lens: Optional[jnp.ndarray] = None,
+              layer=None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """MLA attention; a paged cache (``{"c_pages", "r_pages"}``) follows
+    ``gqa_apply``'s contract: pools stacked over layer groups with this
+    layer's index ``layer``, written and read in place."""
     m = cfg.mla
     b, s, d = x.shape
     h = cfg.n_heads
@@ -534,27 +549,30 @@ def mla_apply(p, x: jnp.ndarray, cfg: ArchConfig, positions: jnp.ndarray,
         if "c_scales" in cache:
             c_pages, c_scales = _pc.append_pages(
                 cache["c_pages"], c_kv, block_table, seq_lens,
-                scales=cache["c_scales"])
+                scales=cache["c_scales"], layer=layer)
             r_pages, r_scales = _pc.append_pages(
                 cache["r_pages"], k_rope, block_table, seq_lens,
-                scales=cache["r_scales"])
+                scales=cache["r_scales"], layer=layer)
         else:
             c_pages = _pc.append_pages(cache["c_pages"], c_kv, block_table,
-                                       seq_lens)
+                                       seq_lens, layer=layer)
             r_pages = _pc.append_pages(cache["r_pages"], k_rope, block_table,
-                                       seq_lens)
+                                       seq_lens, layer=layer)
         q_c = tcec.einsum("bqhn,lhn->bqhl", q_nope, w_uk,
                           site="attn", policy=apol)
         if s == 1:
             o_c = _pa.paged_mla_decode_attention(
                 q_c[:, 0], q_rope[:, 0], c_pages, r_pages, block_table,
                 seq_lens.astype(jnp.int32) + 1, scale=scale,
-                policy=apol, c_scales=c_scales, r_scales=r_scales)[:, None]
+                policy=apol, c_scales=c_scales, r_scales=r_scales,
+                layer=layer)[:, None]
         else:                                   # chunked prefill
             row_pos = seq_lens[:, None].astype(jnp.int32) \
                 + jnp.arange(s, dtype=jnp.int32)[None]
-            c = _pc.gather_pages(c_pages, block_table, scales=c_scales)
-            r = _pc.gather_pages(r_pages, block_table, scales=r_scales)
+            c = _pc.gather_pages(c_pages, block_table, scales=c_scales,
+                                 layer=layer, width=c_kv.shape[-1])
+            r = _pc.gather_pages(r_pages, block_table, scales=r_scales,
+                                 layer=layer, width=k_rope.shape[-1])
             valid = jnp.arange(c.shape[1], dtype=jnp.int32)[None, None] \
                 <= row_pos[..., None]
             o_c = mla_absorbed_attention(q_c, q_rope, c, r, valid, scale,

@@ -75,7 +75,11 @@ def block_apply(p, x: jnp.ndarray, cfg: ArchConfig, spec: BlockSpec,
                 emit_cache: bool = False,
                 block_table=None,
                 seq_lens=None,
-                active=None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+                active=None,
+                layer=None) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """One block.  A paged cache's pools come stacked over layer groups
+    with this block's group index ``layer`` (see ``attention.gqa_apply``);
+    recurrent states come per layer."""
     x = shard_hint(x, "batch", None, None)
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
     _, apply_fn = _MIXERS[spec.mixer]
@@ -84,13 +88,15 @@ def block_apply(p, x: jnp.ndarray, cfg: ArchConfig, spec: BlockSpec,
         y, new_mixer = apply_fn(p["mixer"], h, cfg, positions,
                                 cache=mixer_cache, cache_index=cache_index,
                                 causal=causal, emit_kv=emit_cache,
-                                block_table=block_table, seq_lens=seq_lens)
+                                block_table=block_table, seq_lens=seq_lens,
+                                layer=layer)
     elif spec.mixer == "mla":
         mixer_cache = cache.get("mixer") if cache else None
         y, new_mixer = apply_fn(p["mixer"], h, cfg, positions,
                                 cache=mixer_cache, cache_index=cache_index,
                                 causal=causal,
-                                block_table=block_table, seq_lens=seq_lens)
+                                block_table=block_table, seq_lens=seq_lens,
+                                layer=layer)
     else:
         mixer_cache = cache.get("mixer") if cache else None
         y, new_mixer = apply_fn(p["mixer"], h, cfg, state=mixer_cache)
@@ -190,14 +196,21 @@ def block_paged_cache_spec(cfg: ArchConfig, spec: BlockSpec, slots: int,
 
     ``quantized=True`` stores int8 page payloads plus a per-page fp32 scale
     sidecar per pool (``*_scales (num_pages,)`` — a parallel array, so page
-    ids / block tables / COW / sharding are untouched)."""
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim_
+    ids / block tables / COW / sharding are untouched).
+
+    Each pool's last axis (head dim, latent or rope width) is stored at
+    ``paged_cache.lane_width``: whole lane tiles, so the pool's default
+    layout on a TPU is the row-major one the in-place append and the paged
+    kernel both read."""
+    # (lazy import: serving depends on models)
+    from repro.serving.paged_cache import lane_width
+    kvh, w = cfg.n_kv_heads, lane_width(cfg.head_dim_)
     dt = jnp.int8 if quantized else jnp.dtype(cfg.param_dtype)
     scale = jax.ShapeDtypeStruct((num_pages,), jnp.float32)
     if spec.mixer == "attn":
         out = {
-            "k_pages": jax.ShapeDtypeStruct((num_pages, page_size, kvh, hd), dt),
-            "v_pages": jax.ShapeDtypeStruct((num_pages, page_size, kvh, hd), dt),
+            "k_pages": jax.ShapeDtypeStruct((num_pages, page_size, kvh, w), dt),
+            "v_pages": jax.ShapeDtypeStruct((num_pages, page_size, kvh, w), dt),
         }
         if quantized:
             out["k_scales"] = scale
@@ -207,9 +220,9 @@ def block_paged_cache_spec(cfg: ArchConfig, spec: BlockSpec, slots: int,
         m = cfg.mla
         out = {
             "c_pages": jax.ShapeDtypeStruct(
-                (num_pages, page_size, m.kv_lora_rank), dt),
+                (num_pages, page_size, lane_width(m.kv_lora_rank)), dt),
             "r_pages": jax.ShapeDtypeStruct(
-                (num_pages, page_size, m.qk_rope_head_dim), dt),
+                (num_pages, page_size, lane_width(m.qk_rope_head_dim)), dt),
         }
         if quantized:
             out["c_scales"] = scale

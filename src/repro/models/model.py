@@ -102,13 +102,52 @@ def _remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn)
 
 
+#: Page-pool leaves of a paged cache tree (payloads and their per-page
+#: scale sidecars): shared by every slot, written in place by each step.
+_POOL_KEYS = frozenset(("k_pages", "v_pages", "c_pages", "r_pages",
+                        "k_scales", "v_scales", "c_scales", "r_scales"))
+
+
+def _split_pools(tree):
+    """``(pools, rest)``: the ``_POOL_KEYS`` leaves of a cache tree and all
+    other leaves, as two trees of the same nesting."""
+    pools, rest = {}, {}
+    for key, val in tree.items():
+        if isinstance(val, dict):
+            p, r = _split_pools(val)
+            if p:
+                pools[key] = p
+            if r:
+                rest[key] = r
+        elif key in _POOL_KEYS:
+            pools[key] = val
+        else:
+            rest[key] = val
+    return pools, rest
+
+
+def _merge_trees(a, b):
+    """Inverse of ``_split_pools``."""
+    out = dict(a)
+    for key, val in b.items():
+        out[key] = _merge_trees(out[key], val) if key in out else val
+    return out
+
+
 def _run_blocks(blocks, x, cfg: ArchConfig, positions, causal=True,
                 enc_out=None, caches=None, cache_index=None,
                 emit_cache=False, use_remat=False,
                 block_table=None, seq_lens=None, active=None):
-    """Scan over pattern groups.  Returns (x, new_caches_or_None)."""
+    """Scan over pattern groups.  Returns (x, new_caches_or_None).
 
-    def group_body(x, gparams, gcaches):
+    Cache leaves stacked over groups ride the scan as inputs and outputs
+    (sliced per group), except page pools: those (``_POOL_KEYS``) ride the
+    scan *carry* whole, ``(g, P, page, *tail)``, and each group gets its
+    index ``layer`` to append into and read from them in place.  Sliced per
+    group, a pool would be copied out of and back into the stack on every
+    step; carried, one token's rows per layer are all a step writes."""
+
+    def group_body(x, gparams, gcaches, layer=None):
         new_caches = {}
         for i, spec in enumerate(cfg.pattern):
             key = f"pos{i}"
@@ -118,20 +157,26 @@ def _run_blocks(blocks, x, cfg: ArchConfig, positions, causal=True,
                                 causal=causal, enc_out=enc_out,
                                 emit_cache=emit_cache,
                                 block_table=block_table, seq_lens=seq_lens,
-                                active=active)
+                                active=active, layer=layer)
             if nc is not None:
                 new_caches[key] = nc
         return x, new_caches
 
     if caches is not None:
-        def body(x, xs):
-            gp, gc = xs
-            x, nc = group_body(x, gp, gc)
-            return x, nc
+        pools, states = _split_pools(caches)
+
+        def body(carry, xs):
+            x, pools = carry
+            gp, gs, layer = xs
+            x, nc = group_body(x, gp, _merge_trees(pools, gs), layer)
+            pools, states = _split_pools(nc)
+            return (x, pools), states
         if use_remat:
             body = _remat(body, cfg)
-        x, new_caches = jax.lax.scan(body, x, (blocks, caches))
-        return x, new_caches
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        (x, pools), states = jax.lax.scan(
+            body, (x, pools), (blocks, states, jnp.arange(n, dtype=jnp.int32)))
+        return x, _merge_trees(pools, states)
 
     if emit_cache:
         def body(x, gp):
@@ -327,10 +372,6 @@ def decode_step_paged(params, tokens: jnp.ndarray, caches: Any,
                 x, logit_index.astype(jnp.int32)[..., None], axis=1)
             logits = _logits(params, sel, cfg)
     return logits, new_caches
-
-
-_POOL_KEYS = frozenset(("k_pages", "v_pages", "c_pages", "r_pages",
-                        "k_scales", "v_scales", "c_scales", "r_scales"))
 
 
 def _restore_recurrent_rows(new_caches, old_caches, n_acc, active):

@@ -7,10 +7,14 @@ rank-1-ish contractions, so the win comes from *not staging* dead cache.
 The Pallas kernel therefore never materializes the gathered cache: the
 block table rides as a scalar-prefetch operand and the kv ``BlockSpec``
 index map resolves ``block_table[b, j]`` per grid step, DMA-ing exactly the
-pages a request owns.  Softmax runs online with ``(m, l, acc)`` scratch
-carried across the page axis, and the length mask is generated from its
-structural rule (``col < seq_len`` iota comparison) — the same
-``foreach_ij`` discipline as the flash kernel.
+pages a request owns.  The model step hands every consumer the whole pool
+stacked over layer groups, ``(g, P, page, *tail)``, with the layer index:
+the kernel takes the index as one more scalar-prefetch value and its index
+map picks ``(layer, block_table[b, j])``, so no layer's pool is sliced out
+(a custom-call operand would make XLA copy it out first).  Softmax runs
+online with ``(m, l, acc)`` scratch carried across the page axis, and the
+length mask is generated from its structural rule (``col < seq_len`` iota
+comparison) — the same ``foreach_ij`` discipline as the flash kernel.
 
 Both contractions run ``tcec_core.policy_dot``: the policy resolved from
 the ``"attn"`` site selects fp32-VPU, plain bf16, or the bf16x3/bf16x6
@@ -62,8 +66,8 @@ def _corrected(pol: TcecPolicy) -> bool:
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _paged_kernel(bt_ref, sl_ref, *refs, page, npages, kvh, scale, dot_kw,
-                  has_rope, quantized):
+def _paged_kernel(bt_ref, sl_ref, _layer_ref, *refs, page, npages, kvh,
+                  scale, dot_kw, has_rope, quantized, heads):
     refs = list(refs)
     ks_ref = vs_ref = k2s_ref = None
     if quantized:
@@ -89,13 +93,21 @@ def _paged_kernel(bt_ref, sl_ref, *refs, page, npages, kvh, scale, dot_kw,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     seq_len = sl_ref[bi]
+
+    def rows(ref, g, width):                         # (page, width) of head g
+        row = ref[0, :, g, :width] if heads else ref[0, :, :width]
+        return row.astype(jnp.float32)
+
     # One block holds every kv head of the page (the pool's trailing
-    # (kvh, d) dims are whole, which is what lets Mosaic tile the DMA);
-    # the heads are a static loop over the leading scratch axis.
+    # (kvh, lane-padded d) dims are whole, which is what lets Mosaic tile
+    # the DMA); the heads are a static loop over the leading scratch axis,
+    # each reading its rows as written, without their lane padding.  A
+    # headless pool (MLA's latent pages) is the one-head case.
+    d, d2 = q_ref.shape[-1], None if q2_ref is None else q2_ref.shape[-1]
     for g in range(kvh):
         q = q_ref[0, g].astype(jnp.float32)          # (rep, d)
-        k = k_ref[0, :, g].astype(jnp.float32)       # (page, d)
-        v = v_ref[0, :, g].astype(jnp.float32)       # (page, dv)
+        k = rows(k_ref, g, d)                        # (page, d)
+        v = rows(v_ref, g, d)                        # (page, d)
         if quantized:
             # int8 page payloads: dequantize at this page's scale right
             # after the page DMA — the gather twin multiplies the same
@@ -107,7 +119,7 @@ def _paged_kernel(bt_ref, sl_ref, *refs, page, npages, kvh, scale, dot_kw,
         s = policy_dot(q, k, _QK_DN, **dot_kw)
         if has_rope:
             q2 = q2_ref[0, g].astype(jnp.float32)    # (rep, d2)
-            k2 = k2_ref[0, :, g].astype(jnp.float32) # (page, d2)
+            k2 = rows(k2_ref, g, d2)                 # (page, d2)
             if quantized:
                 k2 = k2 * k2s_ref[bi, ji]
             s = s + policy_dot(q2, k2, _QK_DN, **dot_kw)
@@ -138,45 +150,51 @@ def _paged_kernel(bt_ref, sl_ref, *refs, page, npages, kvh, scale, dot_kw,
 @functools.partial(
     jax.jit, static_argnames=("policy", "scale", "interpret"))
 def _paged_pallas(q, k_pages, v_pages, q2, k2_pages, block_table, seq_lens,
-                  policy: TcecPolicy, scale: float, interpret: bool,
+                  layer, policy: TcecPolicy, scale: float, interpret: bool,
                   k_scales=None, v_scales=None, k2_scales=None):
     b, kvh, rep, d = q.shape
-    page = k_pages.shape[1]
-    dv = v_pages.shape[-1]
+    page = k_pages.shape[2]
     npages = block_table.shape[1]
     has_rope = q2 is not None
     quantized = k_scales is not None
+    heads = k_pages.ndim == 5                    # (g, P, page, kvh, w)
     block_table = block_table.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     # Grid (request, logical page): the page axis is innermost and
     # 'arbitrary' so the (m, l, acc) scratch carries across a request's
-    # pages.  Each step DMAs one physical page with all its kv heads (GQA:
-    # h = kvh * rep, no repeated-head copies).
-    def kv_map(b_, j, bt, *_):
-        return (bt[b_, j], 0, 0, 0)
+    # pages.  Each step DMAs one physical page of the prefetched layer
+    # with all its kv heads (GQA: h = kvh * rep, no repeated-head copies);
+    # the stacked pool's layer axis is squeezed out of the block.
+    def kv_map(b_, j, bt, _sl, ly, *_):
+        return (ly[0], bt[b_, j]) + (0,) * (k_pages.ndim - 2)
 
     def q_map(b_, j, *_):
         del j
         return (b_, 0, 0, 0)
 
-    prefetch = [block_table, seq_lens.astype(jnp.int32)]
+    def kv_spec(pool):
+        return pl.BlockSpec((pl.squeezed, 1) + pool.shape[2:], kv_map)
+
+    prefetch = [block_table, seq_lens.astype(jnp.int32), layer]
     if quantized:
         # Per-page scales gathered to the block table's (b, npages) shape
         # ride in SMEM beside it: one scalar per grid step, no VMEM tile.
-        prefetch += [k_scales[block_table], v_scales[block_table]]
+        prefetch += [k_scales[layer[0], block_table],
+                     v_scales[layer[0], block_table]]
         if has_rope:
-            prefetch += [k2_scales[block_table]]
+            prefetch += [k2_scales[layer[0], block_table]]
     in_specs = [
         pl.BlockSpec((1, kvh, rep, d), q_map),
-        pl.BlockSpec((1, page, kvh, d), kv_map),
-        pl.BlockSpec((1, page, kvh, dv), kv_map),
+        kv_spec(k_pages),
+        kv_spec(v_pages),
     ]
     operands = [q, k_pages, v_pages]
     if has_rope:
         d2 = q2.shape[-1]
         in_specs += [
             pl.BlockSpec((1, kvh, rep, d2), q_map),
-            pl.BlockSpec((1, page, kvh, d2), kv_map),
+            kv_spec(k2_pages),
         ]
         operands += [q2, k2_pages]
 
@@ -184,19 +202,20 @@ def _paged_pallas(q, k_pages, v_pages, q2, k2_pages, block_table, seq_lens,
         num_scalar_prefetch=len(prefetch),
         grid=(b, npages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, rep, dv), q_map),
+        out_specs=pl.BlockSpec((1, kvh, rep, d), q_map),
         scratch_shapes=[
             pltpu.VMEM((kvh, rep, 1), jnp.float32),
             pltpu.VMEM((kvh, rep, 1), jnp.float32),
-            pltpu.VMEM((kvh, rep, dv), jnp.float32),
+            pltpu.VMEM((kvh, rep, d), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_kernel, page=page, npages=npages, kvh=kvh,
                           scale=scale, dot_kw=dot_params(policy),
-                          has_rope=has_rope, quantized=quantized),
+                          has_rope=has_rope, quantized=quantized,
+                          heads=heads),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, rep, dv), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, kvh, rep, d), jnp.float32),
         compiler_params=compiler_params(("parallel", "arbitrary")),
         interpret=interpret,
     )(*prefetch, *operands)
@@ -208,23 +227,38 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
                                   interpret: Optional[bool] = None,
                                   q2=None, k2_pages=None,
                                   k_scales=None, v_scales=None,
-                                  k2_scales=None) -> jnp.ndarray:
+                                  k2_scales=None, layer=None) -> jnp.ndarray:
     """Fused paged decode attention (one query token per request).
 
-    q ``(b, h, d)``; ``k_pages (P, page, kvh, d)``; ``v_pages (P, page,
-    kvh, dv)``; ``block_table (b, npages)``; ``seq_lens (b,)`` — request
+    q ``(b, h, d)``; ``k_pages``/``v_pages (P, page, kvh, w)``, rows of
+    width ``w >= d`` of which the first ``d`` are read (the rest is the
+    pool's lane padding, ``paged_cache.lane_width``); ``block_table (b,
+    npages)``; ``seq_lens (b,)`` — request
     ``i`` attends to its first ``seq_lens[i]`` logical positions; a zero
     length emits zeros.  ``(q2, k2_pages)`` is the optional second score
     operand pair (MLA's rope term, added before the online softmax).
     ``k_scales``/``v_scales``/``k2_scales`` ``(P,)`` fp32 mark int8 pools:
     each page dequantizes at its own scale right after its DMA (int8 page
     reads stream half the bytes of bf16, a quarter of fp32).
-    Returns ``(b, h, dv)`` fp32 for corrected/vpu policies, ``q.dtype``
+    ``layer`` marks every pool stacked over layer groups, ``(g, P, page,
+    kvh, d)`` with scales ``(g, P)``: the kernel takes the whole stacked
+    pool as its operand and reads layer ``layer`` (prefetched beside the
+    block table) through its index map.  Pools one axis short have no
+    kv-head axis, ``(P, page, w)``: MLA's latent pages, one kv head.
+    Returns ``(b, h, d)`` fp32 for corrected/vpu policies, ``q.dtype``
     for the plain bf16 policy (the framework-wide dtype contract).
     """
     pol = resolve_policy(policy, "attn")
     b, h, d = q.shape
-    kvh = k_pages.shape[2]
+    heads = k_pages.ndim == (4 if layer is None else 5)
+    if layer is None:                    # one layer: a stack of one
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        k2_pages = None if k2_pages is None else k2_pages[None]
+        k_scales, v_scales, k2_scales = (
+            None if t is None else t[None]
+            for t in (k_scales, v_scales, k2_scales))
+        layer = 0
+    kvh = k_pages.shape[3] if heads else 1
     if h % kvh:
         raise ValueError(f"q heads {h} not a multiple of kv heads {kvh}")
     rep = h // kvh
@@ -235,10 +269,10 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
     qh = q.reshape(b, kvh, rep, d)
     q2h = None if q2 is None else q2.reshape(b, kvh, rep, q2.shape[-1])
     out = _paged_pallas(qh, k_pages, v_pages, q2h, k2_pages,
-                        block_table, seq_lens, pol, float(scale),
+                        block_table, seq_lens, layer, pol, float(scale),
                         bool(interpret), k_scales=k_scales,
                         v_scales=v_scales, k2_scales=k2_scales)
-    out = out.reshape(b, h, v_pages.shape[-1])
+    out = out.reshape(b, h, d)
     return out if _corrected(pol) else out.astype(q.dtype)
 
 
@@ -248,16 +282,22 @@ def paged_decode_attention_pallas(q, k_pages, v_pages, block_table, seq_lens,
 
 def paged_decode_attention_xla(q, k_pages, v_pages, block_table, seq_lens,
                                *, policy: TcecPolicy | str | None = None,
-                               k_scales=None, v_scales=None) -> jnp.ndarray:
+                               k_scales=None, v_scales=None,
+                               layer=None) -> jnp.ndarray:
     """XLA twin: gather the block table's pages and run the *contiguous*
     ``decode_attention`` on the virtual cache — identical arithmetic to the
     dense decode path by construction (parity is exact per policy).
     Quantized pools (``k_scales``/``v_scales`` given) dequantize during the
-    gather, so the kernel and twin see identical fp32 page values."""
+    gather, so the kernel and twin see identical fp32 page values.
+    ``layer`` marks the pools stacked over layer groups (``gather_pages``
+    reads that layer's pages in one gather)."""
     from repro.models.attention import decode_attention
     pol = resolve_policy(policy, "attn")
-    kv = gather_pages(k_pages, block_table, scales=k_scales)  # (b, Sv, kvh, d)
-    vv = gather_pages(v_pages, block_table, scales=v_scales)
+    d = q.shape[-1]
+    kv = gather_pages(k_pages, block_table, scales=k_scales, layer=layer,
+                      width=d)                        # (b, Sv, kvh, d)
+    vv = gather_pages(v_pages, block_table, scales=v_scales, layer=layer,
+                      width=d)
     o = decode_attention(q[:, None], kv, vv,
                          seq_lens.astype(jnp.int32) - 1, policy=pol)
     return o[:, 0]
@@ -266,32 +306,39 @@ def paged_decode_attention_xla(q, k_pages, v_pages, block_table, seq_lens,
 def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens,
                            *, policy: TcecPolicy | str | None = None,
                            interpret: Optional[bool] = None,
-                           k_scales=None, v_scales=None) -> jnp.ndarray:
+                           k_scales=None, v_scales=None,
+                           layer=None) -> jnp.ndarray:
     """Policy-dispatching paged decode attention (GQA/MHA).
 
     Resolves the ``"attn"`` site from the active ``policy_scope``: a policy
     with ``kernel == "pallas"`` runs the fused Mosaic kernel (native on
     TPU, interpret elsewhere), anything else the gather-based XLA twin.
+    ``layer`` marks the pools stacked over layer groups; both paths read
+    that layer in place.
     """
     pol = resolve_policy(policy, "attn")
     if pol.kernel == "pallas" and pol.backend == "mxu":
         return paged_decode_attention_pallas(
             q, k_pages, v_pages, block_table, seq_lens, policy=pol,
-            interpret=interpret, k_scales=k_scales, v_scales=v_scales)
+            interpret=interpret, k_scales=k_scales, v_scales=v_scales,
+            layer=layer)
     return paged_decode_attention_xla(q, k_pages, v_pages, block_table,
                                       seq_lens, policy=pol,
-                                      k_scales=k_scales, v_scales=v_scales)
+                                      k_scales=k_scales, v_scales=v_scales,
+                                      layer=layer)
 
 
 def paged_mla_decode_attention(q_c, q_rope, c_pages, r_pages, block_table,
                                seq_lens, *, scale: float,
                                policy: TcecPolicy | str | None = None,
                                interpret: Optional[bool] = None,
-                               c_scales=None, r_scales=None) -> jnp.ndarray:
+                               c_scales=None, r_scales=None,
+                               layer=None) -> jnp.ndarray:
     """Paged MLA absorbed decode: ``softmax(q_c c^T + q_r r^T) c``.
 
     ``q_c (b, h, lora)``, ``q_rope (b, h, rope)``; ``c_pages (P, page,
-    lora)``, ``r_pages (P, page, rope)`` hold the *compressed* latent cache
+    >= lora)``, ``r_pages (P, page, >= rope)`` (rows lane-padded, see
+    ``paged_decode_attention_pallas``) hold the *compressed* latent cache
     (never re-expanded — the absorbed matmul-chain restructuring).  Returns
     ``o_c (b, h, lora)``; the caller applies ``W_uv``.  The Pallas path is
     the GQA kernel at ``kvh == 1`` with the rope term as the second score
@@ -299,17 +346,21 @@ def paged_mla_decode_attention(q_c, q_rope, c_pages, r_pages, block_table,
     contiguous decode path runs, so parity is exact per policy.
     ``c_scales``/``r_scales`` ``(P,)`` mark quantized latent pools (the
     latent page serves as both K and V, so its scale applies to both).
+    ``layer`` marks the pools stacked over layer groups, ``(g, P, page,
+    lora)``; the kernel and the twin read that layer in place.
     """
     pol = resolve_policy(policy, "attn")
     if pol.kernel == "pallas" and pol.backend == "mxu":
         return paged_decode_attention_pallas(
-            q_c, c_pages[:, :, None], c_pages[:, :, None], block_table,
+            q_c, c_pages, c_pages, block_table,
             seq_lens, scale=scale, policy=pol, interpret=interpret,
-            q2=q_rope, k2_pages=r_pages[:, :, None],
+            q2=q_rope, k2_pages=r_pages, layer=layer,
             k_scales=c_scales, v_scales=c_scales, k2_scales=r_scales)
     from repro.models.attention import mla_absorbed_attention
-    c = gather_pages(c_pages, block_table, scales=c_scales)  # (b, Sv, lora)
-    r = gather_pages(r_pages, block_table, scales=r_scales)
+    c = gather_pages(c_pages, block_table, scales=c_scales, layer=layer,
+                     width=q_c.shape[-1])              # (b, Sv, lora)
+    r = gather_pages(r_pages, block_table, scales=r_scales, layer=layer,
+                     width=q_rope.shape[-1])
     sv = c.shape[1]
     valid = jnp.arange(sv, dtype=jnp.int32)[None, None] \
         < seq_lens.astype(jnp.int32)[:, None, None]       # (b, 1, Sv)
@@ -320,18 +371,23 @@ def paged_mla_decode_attention(q_c, q_rope, c_pages, r_pages, block_table,
 
 def paged_prefill_attention(q, k_pages, v_pages, block_table, row_pos,
                             *, policy: TcecPolicy | str | None = None,
-                            k_scales=None, v_scales=None) -> jnp.ndarray:
+                            k_scales=None, v_scales=None,
+                            layer=None) -> jnp.ndarray:
     """Chunked-prefill attention against a paged cache (XLA).
 
     ``q (b, s, h, d)`` is a prompt chunk whose tokens sit at absolute
     positions ``row_pos (b, s)``; their K/V must already be appended to the
     pools.  Each row attends causally to every cache position ``<= row_pos``
-    (prefix + intra-chunk causal in one mask).  Returns ``(b, s, h, dv)``.
+    (prefix + intra-chunk causal in one mask).  ``layer`` marks the pools
+    stacked over layer groups (read in place by ``gather_pages``).
+    Returns ``(b, s, h, dv)``.
     """
     pol = resolve_policy(policy, "attn")
     b, sq, h, d = q.shape
-    kv = gather_pages(k_pages, block_table, scales=k_scales)  # (b, Sv, kvh, d)
-    vv = gather_pages(v_pages, block_table, scales=v_scales)
+    kv = gather_pages(k_pages, block_table, scales=k_scales, layer=layer,
+                      width=d)                        # (b, Sv, kvh, d)
+    vv = gather_pages(v_pages, block_table, scales=v_scales, layer=layer,
+                      width=d)
     kvh = kv.shape[2]
     rep = h // kvh
     sv = kv.shape[1]

@@ -6,7 +6,9 @@ A dense decode cache leaf is ``(b, S, *tail)`` with the sequence on axis 1
 batch/sequence axes for a shared pool ``(num_pages, page_size, *tail)``;
 a request owns an ordered list of physical page ids (its *block table*
 row), and logical position ``t`` of request ``i`` lives at
-``pool[block_table[i, t // page_size], t % page_size]``.
+``pool[block_table[i, t // page_size], t % page_size]``.  A pool's last
+axis may be wider than the rows written into it (``lane_width``): appends
+pad a row with zeros and readers take its first ``width`` entries.
 
 Everything here is a pure function on arrays (jit-friendly); ownership and
 free-list bookkeeping are the scheduler's job (``repro.serving.scheduler``).
@@ -25,6 +27,32 @@ from repro.core.quant import TINY, quantize_q
 #: Reserved scratch page.  The allocator never hands it out; block-table
 #: entries of unallocated/finished slots point here.
 NULL_PAGE = 0
+
+#: Lane width of a TPU vector register: the minor tile of every layout.
+LANES = 128
+
+
+def lane_width(width: int) -> int:
+    """A pool row's stored width: ``width`` rounded up to whole lane tiles.
+
+    A TPU lays out an array whose last axis is narrower than a lane tile
+    with another axis minor (the page axis, for a page pool), which
+    neither a per-row scatter nor the paged kernel's page blocks can use
+    as it stands: every step would copy the pools into a row-major layout
+    and back.  Rows of whole lane tiles get the row-major layout by
+    default, the layout the row-major tiles of a narrower row occupy
+    anyway, so padding costs no memory on the chip.  (A pinned
+    non-default layout would do the same, but JAX's persistent
+    compilation cache loses pinned layouts.)"""
+    return -(-width // LANES) * LANES
+
+
+def _pad_rows(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Zero-pad the last axis of ``x`` to ``width``."""
+    pad = width - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -79,8 +107,9 @@ def _token_amax(new: jnp.ndarray, lead: int) -> jnp.ndarray:
 
 def _requantize(pool: jnp.ndarray, old_scales: jnp.ndarray,
                 new_scales: jnp.ndarray) -> jnp.ndarray:
-    """Rescale an int8 pool's payload from per-page ``old_scales`` to the
-    grown ``new_scales`` (both ``(..., P)``, pool ``(..., P, page, *tail)``).
+    """Rescale int8 pages from per-page ``old_scales`` to the grown
+    ``new_scales`` (both ``(..., P)``, pages ``(..., P, page, *tail)``: a
+    whole pool, or the pages an append gathered).
 
     Untouched pages have ``new == old`` so the ratio is exactly ``1.0`` and
     ``round(q * 1.0) == q`` — they round-trip bitwise, which is what keeps
@@ -95,7 +124,7 @@ def _requantize(pool: jnp.ndarray, old_scales: jnp.ndarray,
 
 def append_pages(pool: jnp.ndarray, new: jnp.ndarray,
                  block_table: jnp.ndarray,
-                 seq_lens: jnp.ndarray, scales=None):
+                 seq_lens: jnp.ndarray, scales=None, layer=None):
     """Write ``new (b, s, *tail)`` at logical positions ``seq_lens[i] ..
     seq_lens[i] + s`` of each request into the pool.
 
@@ -103,17 +132,25 @@ def append_pages(pool: jnp.ndarray, new: jnp.ndarray,
     ``seq_lens (b,)`` int32 is each request's current length (the append
     offset).  Returns the updated pool.  Requests whose row should not
     grow (idle slots) must point at ``NULL_PAGE`` so their write is
-    absorbed by the scratch page.
+    absorbed by the scratch page.  Rows narrower than the pool's last axis
+    are zero-padded to it (``lane_width``).
+
+    ``layer`` (a scalar, possibly traced) marks the pool *stacked* over
+    layer groups, ``(g, P, page, *tail)`` with ``scales (g, P)``: the rows
+    land in layer ``layer`` as ``pool.at[layer, phys, off]``, a scatter
+    into the whole stacked pool that XLA performs in place, so no layer's
+    pool is ever sliced out or written back.  ``None`` is the single-layer
+    pool ``(P, page, *tail)``.
 
     ``scales (P,)`` fp32 marks the pool quantized (int8 payload): each
     destination page's scale grows to cover the incoming tokens' amax
-    (scatter-max — scales never shrink mid-residency), the existing payload
-    is requantized by the exact old/new ratio (untouched pages see ratio
-    ``1.0`` and stay bitwise), and the new tokens quantize at the final
-    scale; returns ``(pool, scales)``.  Ghost-lane/speculative writes may
-    conservatively inflate a page's scale before being overwritten — error
-    stays bounded by the inflated ``scale / 2`` per element, never
-    corrupted.
+    (scatter-max — scales never shrink mid-residency), the destination
+    pages' existing payload is requantized by the exact old/new ratio
+    (every other page keeps its scale, so it is left as it is), and the
+    new tokens quantize at the final scale; returns ``(pool, scales)``.
+    Ghost-lane/speculative writes may conservatively inflate a page's
+    scale before being overwritten — error stays bounded by the inflated
+    ``scale / 2`` per element, never corrupted.
 
     Contract: a logical position past the block-table row (``pos //
     page_size >= npages``) is redirected to the scratch page, NOT clamped.
@@ -122,8 +159,10 @@ def append_pages(pool: jnp.ndarray, new: jnp.ndarray,
     prefix sharing that last page may even be another request's boundary
     copy.  Right-padded prefill tail chunks rely on this redirect.
     """
+    lead = () if layer is None else (layer,)
+    new = _pad_rows(new, pool.shape[-1])
     b, s = new.shape[0], new.shape[1]
-    page_size = pool.shape[1]
+    page_size = pool.shape[len(lead) + 1]
     npages = block_table.shape[1]
     pos = seq_lens[:, None].astype(jnp.int32) + jnp.arange(s, dtype=jnp.int32)
     rows = jnp.arange(b, dtype=jnp.int32)[:, None]
@@ -132,13 +171,18 @@ def append_pages(pool: jnp.ndarray, new: jnp.ndarray,
     phys = jnp.where(logical < npages, phys, NULL_PAGE)
     off = pos % page_size
     if scales is None:
-        return pool.at[phys, off].set(new.astype(pool.dtype))
+        return pool.at[lead + (phys, off)].set(new.astype(pool.dtype))
     tok = _token_amax(new, 2) / 127.0                   # (b, s)
-    new_scales = scales.at[phys].max(tok)
-    pool = _requantize(pool, scales, new_scales)
-    s_tok = jnp.maximum(new_scales[phys], TINY)
+    new_scales = scales.at[lead + (phys,)].max(tok)
+    old_tok, new_tok = scales[lead + (phys,)], new_scales[lead + (phys,)]
+    # Requantize just the destination pages (b, s, page, *tail); a page
+    # named twice gets the same values twice.
+    pool = pool.at[lead + (phys,)].set(
+        _requantize(pool[lead + (phys,)], old_tok, new_tok))
+    s_tok = jnp.maximum(new_tok, TINY)
     s_tok = s_tok.reshape(s_tok.shape + (1,) * (new.ndim - 2))
-    return pool.at[phys, off].set(quantize_q(new, s_tok)), new_scales
+    return (pool.at[lead + (phys, off)].set(quantize_q(new, s_tok)),
+            new_scales)
 
 
 def append_prefix_pages(pool: jnp.ndarray, prefix: jnp.ndarray,
@@ -155,8 +199,10 @@ def append_prefix_pages(pool: jnp.ndarray, prefix: jnp.ndarray,
     ``scales`` marks the pool quantized — same scatter-max / ratio-requant
     / quantize-at-final-scale contract as ``append_pages``; returns
     ``(pool, scales)``.  Positions past the block row go to the scratch
-    page (same contract as ``append_pages``).
+    page (same contract as ``append_pages``), and rows are zero-padded to
+    the pool's width as there.
     """
+    prefix = _pad_rows(prefix, pool.shape[-1])
     s = prefix.shape[1] if stacked else prefix.shape[0]
     page_size = pool.shape[2] if stacked else pool.shape[1]
     npages = block_row.shape[0]
@@ -280,19 +326,29 @@ def reset_page_scales(paged_caches, page_ids):
 
 
 def gather_pages(pool: jnp.ndarray, block_table: jnp.ndarray,
-                 scales=None) -> jnp.ndarray:
+                 scales=None, layer=None, width=None) -> jnp.ndarray:
     """Materialize the virtual contiguous cache ``(b, npages * page_size,
     *tail)`` a block table describes (the XLA-twin path; the Pallas kernel
     performs the same gather through its index map without materializing).
+    ``width`` keeps the first ``width`` entries of each row (the rows as
+    written, without their lane padding); ``None`` keeps the whole row.
+
+    ``layer`` marks the pool stacked over layer groups, ``(g, P, page,
+    *tail)`` with ``scales (g, P)``: the rows come from layer ``layer`` in
+    one gather, ``pool[layer, block_table]``, without slicing the layer's
+    pool out first.
 
     ``scales (P,)`` marks the pool quantized: the gathered int8 payload is
     dequantized by each page's scale (fp32 out) — the in-kernel twin
     multiplies the same per-page scalar after its page DMA."""
+    lead = () if layer is None else (layer,)
     b, npages = block_table.shape
-    page_size = pool.shape[1]
-    out = pool[block_table]                      # (b, npages, page, *tail)
+    page_size = pool.shape[len(lead) + 1]
+    out = pool[lead + (block_table,)]            # (b, npages, page, *tail)
+    out = out[..., :width]
+    tail = out.shape[3:]
     if scales is not None:
-        s = scales[block_table]                  # (b, npages)
+        s = scales[lead + (block_table,)]        # (b, npages)
         out = out.astype(jnp.float32) \
-            * s.reshape(s.shape + (1,) * (pool.ndim - 1))
-    return out.reshape((b, npages * page_size) + pool.shape[2:])
+            * s.reshape(s.shape + (1,) * (len(tail) + 1))
+    return out.reshape((b, npages * page_size) + tail)

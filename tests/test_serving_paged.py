@@ -23,7 +23,7 @@ from repro.serving import (append_pages, gather_pages, pages_needed,
                            paged_decode_attention_xla,
                            paged_mla_decode_attention,
                            paged_prefill_attention, NULL_PAGE)
-from repro.serving.paged_cache import write_prefill_prefix
+from repro.serving.paged_cache import lane_width, write_prefill_prefix
 
 from oracles import attention_fp64, max_rel_err
 
@@ -275,6 +275,135 @@ def test_paged_mla_kernel_vs_fp64_oracle(policy):
 
 
 # ---------------------------------------------------------------------------
+# stacked pools: one layer of a (g, P, page, *tail) stack, read and written
+# in place through its layer index
+# ---------------------------------------------------------------------------
+
+GROUPS = 3
+
+
+def _stack(rng, tail, quantized):
+    """A random pool stack ``(GROUPS, POOL, PAGE, *tail)`` and its scale
+    sidecars ``(GROUPS, POOL)`` (``None`` unless ``quantized``)."""
+    shape = (GROUPS, POOL, PAGE) + tail
+    if not quantized:
+        return jnp.asarray(rng.standard_normal(shape).astype(np.float32)), None
+    pool = rng.integers(-127, 128, shape).astype(np.int8)
+    scales = rng.uniform(0.01, 0.05, (GROUPS, POOL)).astype(np.float32)
+    return jnp.asarray(pool), jnp.asarray(scales)
+
+
+def _at(x, layer):
+    return None if x is None else x[layer]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+@pytest.mark.parametrize("layer", [0, GROUPS - 1])
+def test_kernel_reads_a_layer_of_a_stacked_pool_bitwise(layer, kind,
+                                                        quantized):
+    """The Pallas kernel on the whole stack, with the layer index
+    prefetched beside the block table, gives bitwise the kernel's output
+    on that layer's pool alone."""
+    rng = np.random.default_rng(29 + layer)
+    pol = dataclasses.replace(get_policy("bf16x6"), kernel="pallas")
+    bt = jnp.asarray([[3, 7, 1], [5, 2, 4]], np.int32)
+    sl = jnp.asarray(SEQ_LENS)
+    if kind == "gqa":
+        h, kvh, d = 4, 2, 16
+        q = jnp.asarray(rng.standard_normal((B, h, d)).astype(np.float32))
+        (kp, ks), (vp, vs) = (_stack(rng, (kvh, d), quantized)
+                              for _ in range(2))
+
+        def run(k, v, k_s, v_s, lay):
+            return paged_decode_attention_pallas(
+                q, k, v, bt, sl, policy=pol, interpret=True,
+                k_scales=k_s, v_scales=v_s, layer=lay)
+    else:
+        q_c, q_r, *_unused, scale = _mla_case(rng)
+        (kp, ks), (vp, vs) = (_stack(rng, (w,), quantized)
+                              for w in (q_c.shape[-1], q_r.shape[-1]))
+
+        def run(c, r, c_s, r_s, lay):
+            return paged_mla_decode_attention(
+                q_c, q_r, c, r, bt, sl, scale=scale, policy=pol,
+                interpret=True, c_scales=c_s, r_scales=r_s, layer=lay)
+    stacked = jax.jit(run)(kp, vp, ks, vs, jnp.int32(layer))
+    alone = run(kp[layer], vp[layer], _at(ks, layer), _at(vs, layer), None)
+    np.testing.assert_array_equal(np.asarray(stacked), np.asarray(alone))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+def test_append_and_gather_one_layer_of_a_stacked_pool(quantized):
+    """``append_pages`` into layer ``l`` of a stack writes what it writes
+    into ``pool[l]`` alone and leaves every other layer bitwise as it was;
+    ``gather_pages`` reads layer ``l`` as it reads ``pool[l]``."""
+    rng = np.random.default_rng(31)
+    layer = 1
+    pool, scales = _stack(rng, (2, 4), quantized)
+    bt = jnp.asarray([[3, 7, 1], [5, 2, 4]], np.int32)
+    seq = jnp.asarray([6, 15], np.int32)              # crosses a page
+    new = jnp.asarray(rng.standard_normal((B, 3, 2, 4)).astype(np.float32))
+    if quantized:
+        got, got_s = append_pages(pool, new, bt, seq, scales=scales,
+                                  layer=jnp.int32(layer))
+        want, want_s = append_pages(pool[layer], new, bt, seq,
+                                    scales=scales[layer])
+        np.testing.assert_array_equal(np.asarray(got_s[layer]),
+                                      np.asarray(want_s))
+        np.testing.assert_array_equal(
+            np.delete(np.asarray(got_s), layer, 0),
+            np.delete(np.asarray(scales), layer, 0))
+    else:
+        got = append_pages(pool, new, bt, seq, layer=jnp.int32(layer))
+        want = append_pages(pool[layer], new, bt, seq)
+        got_s, want_s = None, None
+    np.testing.assert_array_equal(np.asarray(got[layer]), np.asarray(want))
+    np.testing.assert_array_equal(np.delete(np.asarray(got), layer, 0),
+                                  np.delete(np.asarray(pool), layer, 0))
+    np.testing.assert_array_equal(
+        np.asarray(gather_pages(got, bt, scales=got_s, layer=layer)),
+        np.asarray(gather_pages(want, bt, scales=want_s)))
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+def test_lane_padded_rows_read_back_as_written(quantized):
+    """A pool whose rows are wider than the rows written into it (the
+    engine's pools are ``lane_width`` wide): the append zero-pads each row,
+    and the gather at the written width, the XLA twin and the kernel read
+    back bitwise what they read from a pool of the written width."""
+    rng = np.random.default_rng(37)
+    kvh, d = 2, 16
+    pol = dataclasses.replace(get_policy("bf16x6"), kernel="pallas")
+    bt = jnp.asarray([[3, 7, 1], [5, 2, 4]], np.int32)
+    seq = jnp.asarray([6, 15], np.int32)
+    sl = seq + 3
+    new = jnp.asarray(rng.standard_normal((B, 3, kvh, d)).astype(np.float32))
+    q = jnp.asarray(rng.standard_normal((B, 4, d)).astype(np.float32))
+    read = {}
+    for width in (d, lane_width(d)):
+        pool = jnp.zeros((POOL, PAGE, kvh, width),
+                         jnp.int8 if quantized else jnp.float32)
+        scales = None
+        if quantized:
+            pool, scales = append_pages(pool, new, bt, seq,
+                                        scales=jnp.zeros((POOL,), jnp.float32))
+        else:
+            pool = append_pages(pool, new, bt, seq)
+        assert not np.any(np.asarray(pool)[..., d:])
+        read[width] = (
+            gather_pages(pool, bt, scales=scales, width=d),
+            paged_decode_attention_xla(q, pool, pool, bt, sl,
+                                       policy="fp32_vpu", k_scales=scales,
+                                       v_scales=scales),
+            paged_decode_attention_pallas(q, pool, pool, bt, sl, policy=pol,
+                                          interpret=True, k_scales=scales,
+                                          v_scales=scales))
+    for narrow, wide in zip(read[d], read[lane_width(d)]):
+        np.testing.assert_array_equal(np.asarray(narrow), np.asarray(wide))
+
+
+# ---------------------------------------------------------------------------
 # chunked-prefill attention
 # ---------------------------------------------------------------------------
 
@@ -322,11 +451,11 @@ def _tiny_cfg(mixer):
         remat="none")
 
 
-@pytest.mark.parametrize("mixer", ["attn", "mla"])
-@pytest.mark.parametrize("policy", ["fp32_vpu", "bf16x6"])
-def test_model_paged_decode_matches_dense_decode(mixer, policy):
-    """decode_step_paged reproduces decode_step logits through a whole
-    model: exactly under fp32_vpu, to fp32 roundoff under bf16x6."""
+def _paged_vs_dense_decode(mixer, policy, quantized=False):
+    """Three decode steps of one prefilled request through
+    ``decode_step_paged`` and through the dense ``decode_step``: logits
+    equal exactly under fp32_vpu, to fp32 roundoff under bf16x6; int8
+    pools stay within the quantized band, and do quantize."""
     cfg = _tiny_cfg(mixer)
     rng = jax.random.PRNGKey(0)
     params = init_params(rng, cfg)
@@ -338,7 +467,8 @@ def test_model_paged_decode_matches_dense_decode(mixer, policy):
         from repro.launch.serve import write_prefill_caches
         dense = write_prefill_caches(init_decode_caches(cfg, 1, 24), pf, cfg)
         # paged decode: same prefill scattered into pages
-        pools = init_paged_decode_caches(cfg, slots, 9, page)
+        pools = init_paged_decode_caches(cfg, slots, 9, page,
+                                         quantized=quantized)
         row = jnp.asarray([2, 5, 7], np.int32)
         pools = write_prefill_prefix(pools, pf, row, jnp.int32(0))
         bt = jnp.full((slots, 3), NULL_PAGE, jnp.int32).at[0].set(row)
@@ -349,7 +479,11 @@ def test_model_paged_decode_matches_dense_decode(mixer, policy):
             ld, dense = decode_step(params, tok_d, dense, jnp.int32(11 + i),
                                     cfg)
             lp, pools = decode_step_paged(params, tok_p, pools, bt, seq, cfg)
-            if policy == "fp32_vpu":
+            if quantized:
+                rel = float(jnp.max(jnp.abs(ld[0] - lp[0]))
+                            / jnp.max(jnp.abs(ld[0])))
+                assert 0.0 < rel < 0.1, (i, rel)
+            elif policy == "fp32_vpu":
                 np.testing.assert_array_equal(np.asarray(ld[0]),
                                               np.asarray(lp[0]))
             else:
@@ -359,6 +493,22 @@ def test_model_paged_decode_matches_dense_decode(mixer, policy):
             tok_d = jnp.argmax(ld, -1)[:, None].astype(jnp.int32)
             tok_p = tok_p.at[0].set(tok_d[0])
             seq = seq.at[0].add(1)
+
+
+@pytest.mark.parametrize("mixer", ["attn", "mla"])
+@pytest.mark.parametrize("policy", ["fp32_vpu", "bf16x6"])
+def test_model_paged_decode_matches_dense_decode(mixer, policy):
+    """decode_step_paged reproduces decode_step logits through a whole
+    model: exactly under fp32_vpu, to fp32 roundoff under bf16x6."""
+    _paged_vs_dense_decode(mixer, policy)
+
+
+@pytest.mark.parametrize("mixer", ["attn", "mla"])
+@pytest.mark.parametrize("policy", ["fp32_vpu", "bf16x6"])
+def test_model_paged_decode_matches_dense_decode_int8_pools(mixer, policy):
+    """The same through int8 pools with per-page scales: the stacked
+    scale sidecars ride the layer scan beside their pools."""
+    _paged_vs_dense_decode(mixer, policy, quantized=True)
 
 
 @pytest.mark.parametrize("policy", ["fp32_vpu", "bf16x6"])
